@@ -52,7 +52,8 @@ from repro.launch.train import build_train_step, init_state
 steps = {steps}
 batch, seq = 8, {seq}
 cfg = get_smoke_config("qwen3-0.6b").with_(dtype="float32")
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8, 1), ("data", "model"))
 w = 8
 
 results = {{}}
@@ -103,7 +104,8 @@ def main(steps: int = STEPS, smoke: bool = False):
     r = subprocess.run(
         [sys.executable, "-c", _CHILD.format(steps=steps, seq=seq)],
         capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd=REPO,
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+        cwd=REPO,
     )
     line = next(
         (l for l in r.stdout.splitlines() if l.startswith("BENCH_JSON ")),

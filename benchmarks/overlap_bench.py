@@ -43,7 +43,8 @@ from repro.launch.hlo_stats import collective_bytes
 
 steps = {steps}
 smoke = {smoke}
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8, 1), ("data", "model"))
 key = jax.random.PRNGKey(0)
 w = 8
 
@@ -92,7 +93,8 @@ def main(steps: int = STEPS, smoke: bool = False):
     r = subprocess.run(
         [sys.executable, "-c", _CHILD.format(steps=steps, smoke=smoke)],
         capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd=REPO,
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+        cwd=REPO,
     )
     line = next(
         (l for l in r.stdout.splitlines() if l.startswith("BENCH_JSON ")),

@@ -3,7 +3,3 @@
 Reproduction of "Shifted Compression Framework: Generalizations and
 Improvements" grown toward a production-scale jax system; see ROADMAP.md.
 """
-
-from repro import compat as _compat
-
-_compat.install()

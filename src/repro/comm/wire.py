@@ -2,16 +2,19 @@
 
 One home for the helpers that were duplicated between the Channel
 uplink (``repro.comm.channel``) and the codec-driven collectives
-(``repro.dist.collectives``): worker key derivation, the vmapped
-per-worker encode, and the meta-free guard for forwarded-payload
-transports.  Imports only jax — safe for both sides of the
+(``repro.dist.collectives``): worker key derivation, the per-worker
+map (``on_workers``) and encode, and the meta-free guard for
+forwarded-payload transports.  Imports only jax — safe for both sides of the
 comm <-> dist boundary.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def leaf_key(key: jax.Array, leaf_index: int) -> jax.Array:
@@ -41,6 +44,36 @@ def worker_keys(codec, key: jax.Array, w: int) -> jax.Array:
     return jax.random.split(key, w)
 
 
+def on_workers(fn, *args, in_axes=0):
+    """``jax.vmap(fn, in_axes)(*args)`` over the leading worker axis W.
+
+    Under an ambient data-parallel mesh — worker axes ("pod", "data")
+    that split W, every other axis of size 1 — the map runs inside a
+    ``shard_map`` over the worker axes, so each device maps only its own
+    workers' rows.  GSPMD cannot partition a Pallas (Mosaic) kernel
+    such as the fused q8 codec's; the manual map needs no partitioning.
+    Without such a mesh (CPU tests, tensor-parallel meshes) it is the
+    plain vmap.  Every output is worker-stacked.
+    """
+    mapped = jax.vmap(fn, in_axes=in_axes)
+    mesh = jax.sharding.get_abstract_mesh()
+    sizes = dict(mesh.shape) if not mesh.empty else {}
+    waxes = tuple(a for a in ("pod", "data") if sizes.get(a, 1) > 1)
+    axes = in_axes if isinstance(in_axes, tuple) else (in_axes,) * len(args)
+    w = next(jax.tree_util.tree_leaves(x)[0].shape[0]
+             for x, ax in zip(args, axes) if ax == 0)
+    if (not waxes or w % math.prod(sizes[a] for a in waxes)
+            or any(n > 1 for a, n in sizes.items() if a not in waxes)):
+        return mapped(*args)
+    spec = P(waxes)
+    return jax.shard_map(
+        mapped,
+        in_specs=tuple(spec if ax == 0 else P() for ax in axes),
+        out_specs=spec,
+        check_vma=False,
+    )(*args)
+
+
 def encode_workers(codec, key: jax.Array, leaf: jax.Array):
     """Encode each worker row of a worker-stacked leaf.
 
@@ -48,7 +81,8 @@ def encode_workers(codec, key: jax.Array, leaf: jax.Array):
     a leading W axis; for shared-pattern codecs every row is encoded
     with the same key, so meta rows are identical).
     """
-    return jax.vmap(codec.encode)(worker_keys(codec, key, leaf.shape[0]), leaf)
+    return on_workers(codec.encode, worker_keys(codec, key, leaf.shape[0]),
+                      leaf)
 
 
 def encode_decode_workers(codec, key: jax.Array, leaf: jax.Array):
@@ -64,7 +98,7 @@ def encode_decode_workers(codec, key: jax.Array, leaf: jax.Array):
         payload, meta = codec.encode(k, row)
         return payload, codec.decode(payload, meta, sds)
 
-    return jax.vmap(enc_dec)(worker_keys(codec, key, leaf.shape[0]), leaf)
+    return on_workers(enc_dec, worker_keys(codec, key, leaf.shape[0]), leaf)
 
 
 def encode_meta_free(codec, key: jax.Array, block: jax.Array):

@@ -41,7 +41,6 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comm.wire import encode_meta_free, encode_workers
@@ -320,12 +319,12 @@ def q8_ring_tree_mean(
             outs.append((acc / w_glob[i]).astype(x.dtype))
         return tuple(outs)
 
-    out_leaves = shard_map(
+    out_leaves = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(),) + in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(key, *leaves)
     return jax.tree_util.tree_unflatten(treedef, list(out_leaves))
 

@@ -11,7 +11,11 @@ size).
 On the production mesh the worker axis is sharded ``P(("pod","data"))``,
 so the vmap body runs as W parallel per-device gradient computations and
 the stacked leaves never materialize unsharded — the compressed
-collectives in ``repro.dist.collectives`` consume them in place.
+collectives in ``repro.dist.collectives`` consume them in place.  Under
+the train step's ambient data-parallel mesh the map is a ``shard_map``
+over the worker axes (``repro.comm.wire.on_workers``): each device
+computes its own workers' gradients, and a Pallas codec encoding in
+the backward pass (the fused-VJP path) needs no GSPMD partitioning.
 """
 
 from __future__ import annotations
@@ -20,6 +24,8 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.comm.wire import on_workers
 
 tmap = jax.tree_util.tree_map
 
@@ -57,11 +63,11 @@ def per_worker_grads(
     ``metrics`` leaves are averaged over the worker axis.
     """
 
-    def one(b):
-        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(params, b)
+    def one(p, b):
+        (loss, aux), g = jax.value_and_grad(loss_fn, has_aux=True)(p, b)
         return g, loss, aux
 
-    wgrads, losses, aux = jax.vmap(one)(wbatch)
+    wgrads, losses, aux = on_workers(one, params, wbatch, in_axes=(None, 0))
     loss = jnp.mean(losses)
     metrics = tmap(lambda a: jnp.mean(a, axis=0), aux)
     return wgrads, loss, metrics

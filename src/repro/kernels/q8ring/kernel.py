@@ -27,6 +27,14 @@ tie validation to TPU hardware (same policy as ``kernels.natural``).
 
 Layout: (rows, 128) lanes, tiled in ``block_rows`` row blocks; the 3-d
 chunk variant sees the ring buffer as (n_chunks, rows, 128).
+
+Scales cross the kernel boundary LANE-DENSE: one (1, 128) row per tile
+(the scale broadcast over the lanes) in an (n_tiles, 1, 128) array.
+Mosaic accepts that block (its last two dims equal the array's) where a
+(1, 1) block over an (n_tiles, 1) array breaks the (8, 128) tiling rule,
+and a vector store works for a 1-row tile where a scalar store to VMEM
+does not.  The public wrappers keep the compact (n_tiles, 1) scales that
+travel on the wire.
 """
 
 from __future__ import annotations
@@ -44,31 +52,44 @@ LEVELS = 127              # int8 quantization lattice [-127, 127]
 SCALE_FLOOR = 1e-30       # well above subnormal: tiny/LEVELS must not flush
 
 
-def _q8_quantize_kernel(x_ref, u_ref, q_ref, s_ref):
-    """One tile: scale = max|x|/LEVELS, q = stochastic_round(x/scale)."""
-    x = x_ref[...].astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), SCALE_FLOOR) / LEVELS
+def _quantize_tile(x, u, q_ref, s_ref):
+    """scale = max|x|/LEVELS, q = stochastic_round(x/scale); the scale is
+    kept (1, 1) so it broadcasts as a vector and stores lane-dense."""
+    scale = jnp.maximum(jnp.max(jnp.abs(x), keepdims=True),
+                        SCALE_FLOOR) / LEVELS
     y = x / scale
     lo = jnp.floor(y)
-    up = (u_ref[...] < (y - lo)).astype(jnp.float32)
+    up = (u < (y - lo)).astype(jnp.float32)
     q_ref[...] = (lo + up).astype(jnp.int8)
-    s_ref[0, 0] = scale
+    s_ref[...] = jnp.broadcast_to(scale, s_ref.shape)
+
+
+def _q8_quantize_kernel(x_ref, u_ref, q_ref, s_ref):
+    _quantize_tile(x_ref[...].astype(jnp.float32), u_ref[...], q_ref, s_ref)
 
 
 def _q8_chunk_kernel(cid_ref, x_ref, u_ref, q_ref, s_ref):
     """Chunk-select variant: x_ref is the (1, block, LANE) tile of the
     chunk picked by the scalar-prefetch id (see index_map below)."""
-    x = x_ref[0].astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), SCALE_FLOOR) / LEVELS
-    y = x / scale
-    lo = jnp.floor(y)
-    up = (u_ref[...] < (y - lo)).astype(jnp.float32)
-    q_ref[...] = (lo + up).astype(jnp.int8)
-    s_ref[0, 0] = scale
+    _quantize_tile(x_ref[0].astype(jnp.float32), u_ref[...], q_ref, s_ref)
 
 
 def _q8_dequant_add_kernel(q_ref, s_ref, acc_ref, o_ref):
-    o_ref[...] = acc_ref[...] + q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    o_ref[...] = acc_ref[...] + q_ref[...].astype(jnp.float32) * s_ref[...]
+
+
+def _scale_spec(index_map):
+    """Block of the lane-dense scale array: tile i's (1, 128) row."""
+    return pl.BlockSpec((None, 1, LANE), index_map)
+
+
+def _scale_shape(n_tiles: int):
+    return jax.ShapeDtypeStruct((n_tiles, 1, LANE), jnp.float32)
+
+
+def _compact(scales3):
+    """(n_tiles, 1, 128) lane-dense scales -> the (n_tiles, 1) wire form."""
+    return scales3[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -81,17 +102,18 @@ def q8_quantize_2d(x, u, *, block_rows: int = DEFAULT_BLOCK_ROWS,
     assert lane == LANE and u.shape == x.shape and r % block_rows == 0
     grid = (r // block_rows,)
     tile = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    return pl.pallas_call(
+    q, s3 = pl.pallas_call(
         _q8_quantize_kernel,
         grid=grid,
         in_specs=[tile, tile],
-        out_specs=[tile, pl.BlockSpec((1, 1), lambda i: (i, 0))],
+        out_specs=[tile, _scale_spec(lambda i: (i, 0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, jnp.int8),
-            jax.ShapeDtypeStruct((r // block_rows, 1), jnp.float32),
+            _scale_shape(r // block_rows),
         ],
         interpret=interpret,
     )(x, u)
+    return q, _compact(s3)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -118,18 +140,19 @@ def q8_quantize_chunk_3d(chunks, u, chunk_id, *,
         ],
         out_specs=[
             pl.BlockSpec((block_rows, LANE), lambda i, cid: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, cid: (i, 0)),
+            _scale_spec(lambda i, cid: (i, 0, 0)),
         ],
     )
-    return pl.pallas_call(
+    q, s3 = pl.pallas_call(
         _q8_chunk_kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((r, LANE), jnp.int8),
-            jax.ShapeDtypeStruct((r // block_rows, 1), jnp.float32),
+            _scale_shape(r // block_rows),
         ],
         interpret=interpret,
     )(jnp.asarray(chunk_id, jnp.int32).reshape(1), chunks, u)
+    return q, _compact(s3)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -139,14 +162,14 @@ def q8_dequant_add_2d(q, scales, acc, *, block_rows: int = DEFAULT_BLOCK_ROWS,
     (R//block_rows, 1) f32, acc: (R, 128) f32."""
     r, lane = q.shape
     assert lane == LANE and acc.shape == q.shape and r % block_rows == 0
-    assert scales.shape == (r // block_rows, 1)
-    grid = (r // block_rows,)
+    nb = r // block_rows
+    assert scales.shape == (nb, 1)
     tile = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     return pl.pallas_call(
         _q8_dequant_add_kernel,
-        grid=grid,
-        in_specs=[tile, pl.BlockSpec((1, 1), lambda i: (i, 0)), tile],
+        grid=(nb,),
+        in_specs=[tile, _scale_spec(lambda i: (i, 0, 0)), tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         interpret=interpret,
-    )(q, scales, acc)
+    )(q, jnp.broadcast_to(scales[:, :, None], (nb, 1, LANE)), acc)

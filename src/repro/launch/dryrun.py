@@ -36,19 +36,12 @@ from repro.launch.train import (
     batch_pspecs,
     build_train_step,
     init_state,
+    named_shardings,
     state_pspecs,
 )
 from repro.models import model as M
 
 tmap = jax.tree_util.tree_map
-
-
-def _named(mesh, specs):
-    return tmap(
-        lambda sp: NamedSharding(mesh, sp),
-        specs,
-        is_leaf=lambda x: isinstance(x, P),
-    )
 
 
 def skip_reason(arch: str, shape: InputShape) -> str | None:
@@ -160,8 +153,8 @@ def lower_train(cfg: ModelConfig, shape: InputShape, mesh,
     with jax.sharding.set_mesh(mesh):
         jfn = jax.jit(
             step,
-            in_shardings=(_named(mesh, st_specs), _named(mesh, b_specs)),
-            out_shardings=(_named(mesh, st_specs), None),
+            in_shardings=(named_shardings(st_specs, mesh), named_shardings(b_specs, mesh)),
+            out_shardings=(named_shardings(st_specs, mesh), None),
             donate_argnums=(0,),
         )
         return jfn.lower(state_shapes, batch_shapes)
@@ -186,7 +179,7 @@ def lower_eval(cfg: ModelConfig, shape: InputShape, mesh):
     with jax.sharding.set_mesh(mesh):
         jfn = jax.jit(
             eval_step,
-            in_shardings=(_named(mesh, p_specs), _named(mesh, b_specs)),
+            in_shardings=(named_shardings(p_specs, mesh), named_shardings(b_specs, mesh)),
             out_shardings=None,
         )
         return jfn.lower(params_shapes, batch_shapes)
@@ -216,12 +209,12 @@ def lower_decode(cfg: ModelConfig, shape: InputShape, mesh):
         jfn = jax.jit(
             step,
             in_shardings=(
-                _named(mesh, p_specs),
-                _named(mesh, s_specs),
+                named_shardings(p_specs, mesh),
+                named_shardings(s_specs, mesh),
                 NamedSharding(mesh, tok_spec),
                 NamedSharding(mesh, P()),
             ),
-            out_shardings=(None, _named(mesh, s_specs)),
+            out_shardings=(None, named_shardings(s_specs, mesh)),
         )
         return jfn.lower(params_shapes, state_shapes, tok, pos)
 

@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ModelConfig
 from repro.dist import params_pspecs, validate_pspecs
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 
@@ -176,6 +177,7 @@ def main(argv=None):
                     dest="trainer_steps", type=int, default=6,
                     help="trainer steps to run in the fleet demo")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.serve_fleet > 0:
         import json

@@ -64,7 +64,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.comm import (
     CHANNEL_MODES,
@@ -88,6 +88,7 @@ from repro.dist import (
     worker_stacked_pspec,
 )
 from repro.data.tokens import TokenStream
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, n_workers
 from repro.models import model as M
 from repro.optim import make_optimizer
@@ -244,6 +245,14 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh, w: int,
         return M.train_loss(params, cfg, batch, param_tap=tap)
 
     def train_step(state: TrainState, batch):
+        # the step's mesh is the ambient mesh while it traces: the
+        # per-worker maps (``repro.comm.wire.on_workers``) read it
+        if mesh is None:
+            return _step(state, batch)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return _step(state, batch)
+
+    def _step(state: TrainState, batch):
         wbatch = split_batch(batch, w)
         # the round key is split BEFORE the backward pass (the fused
         # path derives its message keys from ``sub``); the split is
@@ -376,6 +385,32 @@ def state_pspecs(state_shapes, mesh, tcfg: TrainConfig):
 def batch_pspecs(batch_shapes, mesh):
     axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     return tmap(lambda _: P(axes), batch_shapes)
+
+
+def named_shardings(specs, mesh):
+    """A PartitionSpec tree as NamedShardings on ``mesh``."""
+    return tmap(lambda sp: NamedSharding(mesh, sp), specs,
+                is_leaf=lambda x: isinstance(x, P))
+
+
+def init_placed_state(key, cfg: ModelConfig, tcfg: TrainConfig, mesh,
+                      w: int):
+    """``init_state`` built directly in its mesh layout (``state_pspecs``:
+    optimizer moments sharded over the data axis, worker shifts one row
+    per data slice), so no device ever holds the whole state.  Returns
+    ``(state, state_shardings)``."""
+    init = lambda k: init_state(k, cfg, tcfg, w)  # noqa: E731
+    sh = named_shardings(state_pspecs(jax.eval_shape(init, key), mesh, tcfg),
+                         mesh)
+    return jax.jit(init, out_shardings=sh)(key), sh
+
+
+def jit_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh, w: int,
+                   state_shardings, *, diag: bool = False):
+    """The jitted step: the state is donated (its buffers are reused for
+    the new state) and stays in ``state_shardings`` from step to step."""
+    return jax.jit(build_train_step(cfg, tcfg, mesh, w, diag=diag),
+                   out_shardings=(state_shardings, None), donate_argnums=0)
 
 
 # ---------------------------------------------------------------------------
@@ -578,9 +613,13 @@ def main(argv=None):
                          "(encode/reduce/apply) and include the span "
                          "table in the run summary")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
-    cfg = cfg.with_(dtype="float32")
+    if args.smoke:
+        # the CPU-sized smoke variant runs in f32; a full config trains
+        # in its published dtype (bf16 params, shifts and activations)
+        cfg = cfg.with_(dtype="float32")
     comp = CompressionConfig(
         enabled=not args.no_compression,
         compressor=args.compressor,
@@ -644,9 +683,11 @@ def main(argv=None):
                        warmup_steps=max(1, args.steps // 10),
                        compression=comp)
 
-    state = init_state(jax.random.PRNGKey(0), cfg, tcfg, w)
-    step_fn = jax.jit(build_train_step(cfg, tcfg, mesh, w, diag=obs_on))
+    state, state_sh = init_placed_state(jax.random.PRNGKey(0), cfg, tcfg,
+                                        mesh, w)
+    step_fn = jit_train_step(cfg, tcfg, mesh, w, state_sh, diag=obs_on)
     stream = TokenStream(cfg, args.seq, args.batch)
+    batch_sh = named_shardings(batch_pspecs(stream.batch(0), mesh), mesh)
 
     predicted_step_s = None
     if obs_on:
@@ -728,8 +769,9 @@ def main(argv=None):
         )
         downlink = build_transport(comp, cfg, SimChannel(), w=w,
                                    params_like=params_shapes)
+        # the step donates the trainer's params; the fleet keeps copies
         bridge = TrainerFleetBridge(
-            cfg, state.params, downlink["model"],
+            cfg, tmap(jnp.copy, state.params), downlink["model"],
             n_replicas=args.serve_fleet, publish_every=comp.publish_every,
             stale_k=args.stale_k, key=jax.random.PRNGKey(1),
             obs=sink,
@@ -759,12 +801,13 @@ def main(argv=None):
         for i in range(args.steps):
             ts = time.perf_counter()
             with step_ctx():
-                state, metrics = step_fn(state, stream.batch(i))
+                state, metrics = step_fn(
+                    state, jax.device_put(stream.batch(i), batch_sh))
                 if sink is not None or recorder is not None:
                     jax.block_until_ready(state.params)
             step_s = time.perf_counter() - ts
             if bridge is not None:
-                bridge.on_step(state.params, i + 1)
+                bridge.on_step(tmap(jnp.copy, state.params), i + 1)
             if sink is not None:
                 sink.emit(obs.step_record(
                     i,

@@ -41,22 +41,19 @@ def shard_hint(x, *spec):
     crucial under vmap, where forcing replication would fight the mapped
     worker axis);  the string ``"rep"`` forces the dim replicated (e.g.
     gathering the key sequence once before streamed attention).
-    No-op when there is no mesh (CPU smoke tests).
+    No-op unless the ambient mesh has a "model" axis wider than one
+    device (CPU smoke tests and the data-parallel trainer have none);
+    a constraint the mesh rejects raises.
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or "model" not in mesh.axis_names:
-            return x
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        dims = tuple(
-            P.UNCONSTRAINED if d is None else (None if d == "rep" else d)
-            for d in spec
-        )
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*dims))
-        )
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or dict(mesh.shape).get("model", 1) == 1:
         return x
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    dims = tuple(
+        P.UNCONSTRAINED if d is None else (None if d == "rep" else d)
+        for d in spec
+    )
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*dims)))
 
 
 def wire_boundary(wire, key, x, e):

@@ -38,10 +38,7 @@ def _host_clock_ok() -> bool:
     """True when a perf_counter span is meaningful — i.e. we are not
     inside a jax trace (where Python time measures TRACING, not the
     computation)."""
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:  # noqa: BLE001 — newer jax moved/removed the probe
-        return True
+    return jax.core.trace_ctx.is_top_level()
 
 
 class SpanRecorder:

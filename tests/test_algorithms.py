@@ -81,9 +81,14 @@ def test_theorem2_dcgd_star_exact(prob, q):
         prob, DCGDShift(q, StarShift()), g, 6000, use_star=True, seed=2
     )
     assert tr.rel_err[-1] < 5e-5
-    # linearity: log error decreases roughly monotonically (windowed)
+    # linearity: the windowed error decreases every window until it
+    # reaches the float32 floor (~1e-13, by about window 8), where it
+    # only jitters
     w = tr.rel_err[::500]
-    assert all(w[i + 1] < w[i] for i in range(len(w) - 2))
+    floor = 1e-12
+    above = [i for i in range(len(w) - 1) if w[i] > floor]
+    assert len(above) >= 6
+    assert all(w[i + 1] < w[i] for i in above)
 
 
 def test_theorem2_star_with_biased_c(prob, q):

@@ -14,6 +14,7 @@ import pytest
 
 from repro.dist.collectives import dense_mean, randk_shared_mean
 from repro.dist.worker_grads import per_worker_grads, split_batch
+from repro.launch.mesh import make_mesh
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -73,7 +74,8 @@ _RING_TEST = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.dist.collectives import q8_ring_tree_mean
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8, 1), ("data", "model"))
     key = jax.random.PRNGKey(0)
     w = 8
     tree = {"a": jax.random.normal(key, (w, 1000)),
@@ -113,7 +115,8 @@ _SHARDING_TEST = textwrap.dedent("""
     from repro.models import model as M
     from repro.configs import get_smoke_config
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     for arch in ("qwen3-0.6b", "qwen2-moe-a2.7b", "rwkv6-3b", "zamba2-1.2b"):
         cfg = get_smoke_config(arch)
         shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
@@ -196,7 +199,7 @@ def test_worker_stacked_pspec_prepends_worker_axes():
         ((1, 1), ("data", "model"), "data"),
         ((1, 1, 1), ("pod", "data", "model"), ("pod", "data")),
     ):
-        mesh = jax.make_mesh(mesh_shape, axes)
+        mesh = make_mesh(mesh_shape, axes)
         wspecs = jax.tree_util.tree_map(
             lambda sp: worker_stacked_pspec(mesh, sp), specs, is_leaf=is_p
         )

@@ -353,7 +353,8 @@ _E2E = textwrap.dedent("""
     from repro.launch.train import build_train_step, init_state
 
     cfg = get_smoke_config("qwen3-0.6b").with_(dtype="float32")
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8, 1), ("data", "model"))
     w, batch, seq, steps = 8, 8, 32, 2
 
     states = {}
@@ -405,7 +406,8 @@ _AWKWARD = textwrap.dedent("""
 
     # odd world size; leaf sizes not divisible by lanes or world size;
     # a scalar-per-worker leaf — mirrors tests/test_overlap.py
-    mesh = jax.make_mesh((5,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((5,), ("data",))
     key = jax.random.PRNGKey(0)
     w = 5
     tree = {"a": jax.random.normal(key, (w, 777)),

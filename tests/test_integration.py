@@ -67,6 +67,7 @@ _SHARDED_LOSS = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_smoke_config
     from repro.dist import params_pspecs, validate_pspecs
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M
 
     for arch in ("qwen3-0.6b", "qwen2-moe-a2.7b"):
@@ -80,7 +81,7 @@ _SHARDED_LOSS = textwrap.dedent("""
         batch = {"tokens": toks}
         loss_ref, _ = M.train_loss(params, cfg, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         specs = validate_pspecs(params, params_pspecs(params), mesh)
         sharded = jax.device_put(
             params, jax.tree.map(lambda sp: NamedSharding(mesh, sp), specs,

@@ -110,6 +110,21 @@ def test_train_cli_ef21_8dev_subprocess():
     assert "EF21_CLI_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-3000:]
 
 
+def test_compile_cache_follows_env_and_stays_off_on_cpu(monkeypatch,
+                                                         tmp_path):
+    """The entry points' cache: JAX_COMPILATION_CACHE_DIR wins when set;
+    otherwise the checkout path, except on the CPU backend, where the
+    cache is left off and JAX's setting untouched."""
+    from repro.launch.cache import use_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    was = jax.config.jax_compilation_cache_dir
+    assert use_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == was
+
+
 def test_diana_matches_dense_direction():
     """With an Identity compressor, DIANA's estimator equals the plain
     mean gradient (g_bar = h_bar + mean(g - h)) — the launch path must be

@@ -35,7 +35,7 @@ from repro.comm import SimChannel, build_transport
 from repro.configs import get_smoke_config
 from repro.configs.base import CompressionConfig, TrainConfig
 from repro.data.tokens import TokenStream
-from repro.launch.mesh import make_host_mesh, n_workers
+from repro.launch.mesh import make_host_mesh, make_mesh, n_workers
 from repro.launch.train import build_train_step, init_state
 from repro.models import model as M
 
@@ -295,6 +295,14 @@ def test_span_times_host_work_only_when_recording():
     assert snap["host/timed"]["count"] == 3
     assert snap["host/timed"]["total_s"] >= 0.0
 
+    def traced(x):
+        with obs.span("host/traced"):           # tracing time, not compute
+            return x + 1.0
+
+    with obs.recording(rec):
+        jax.jit(traced)(jnp.float32(0.0))
+    assert "host/traced" not in rec.snapshot()
+
 
 def test_stamp_recorder_windows():
     rec = obs.StampRecorder()
@@ -341,7 +349,7 @@ def test_measured_hide_lands_in_tune_plan(tmp_path):
     ``search_plan`` into the produced ``TunePlan`` and survives the
     strict-JSON round trip (what ``repro.tune`` consumes in place of
     the nominal constant)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     wtree = _wtree(jax.random.PRNGKey(0), w=4)
     kw = dict(modes=("dense", "q8_ring_overlap"), bucket_grid=(1 << 20,),
               link=tune.LinkModel.nominal(), verify_top=0,

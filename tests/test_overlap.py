@@ -233,7 +233,8 @@ _CONTRACT = textwrap.dedent("""
     from repro.comm import AsyncChannel, MeshChannel
     from repro.core.compressors import NaturalCompression
 
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8, 1), ("data", "model"))
     key = jax.random.PRNGKey(0)
     w = 8
     tree = {"a": jax.random.normal(key, (w, 1000)),
@@ -294,7 +295,8 @@ _AWKWARD = textwrap.dedent("""
 
     # odd world size; leaf sizes not divisible by lanes or world size;
     # a scalar-per-worker leaf
-    mesh = jax.make_mesh((5,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((5,), ("data",))
     key = jax.random.PRNGKey(0)
     w = 5
     tree = {"a": jax.random.normal(key, (w, 777)),

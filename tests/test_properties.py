@@ -156,10 +156,11 @@ def test_validate_pspecs_always_divides(dims):
     import os
     from jax.sharding import PartitionSpec as P
     from repro.dist.sharding import validate_pspecs
+    from repro.launch.mesh import make_mesh
 
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shapes = [jax.ShapeDtypeStruct(tuple(dims), jnp.float32)]
     specs = [P(*( ["model"] + [None] * (len(dims) - 1) ))]
     fixed = validate_pspecs(shapes, specs, mesh)

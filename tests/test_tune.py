@@ -20,6 +20,7 @@ from repro.comm.wire import encode_workers, leaf_key
 from repro.configs.base import CompressionConfig
 from repro.core.shift_rules import DianaShift
 from repro.core.compressors import NaturalCompression
+from repro.launch.mesh import make_mesh
 from repro.tune.model import Candidate, TUNABLE_MODES, predicted_wire_bits, wire_codec
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -215,7 +216,7 @@ def test_plan_version_and_unknown_fields_rejected():
 
 
 def test_fingerprint_sensitivity():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32)}
     fp = tune.plan_fingerprint(params, mesh, 4, "natural")
     assert fp == tune.plan_fingerprint(params, mesh, 4, "natural")
@@ -232,7 +233,7 @@ def test_fingerprint_sensitivity():
 
 
 def test_autotune_restricted_modes_do_not_poison_full_cache(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     comp = CompressionConfig(comm_mode="auto")
     params = {"w": jax.ShapeDtypeStruct((16, 8), jnp.float32)}
     kw = dict(cache_dir=str(tmp_path), link=tune.LinkModel.nominal(),
@@ -248,7 +249,7 @@ def test_autotune_restricted_modes_do_not_poison_full_cache(tmp_path):
 
 
 def test_autotune_lazy_analysis_only_on_miss(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     comp = CompressionConfig(comm_mode="auto")
     params = {"w": jax.ShapeDtypeStruct((16, 8), jnp.float32)}
     calls = []
@@ -288,7 +289,7 @@ def test_cached_plan_miss_on_corrupt_or_mismatched_file(tmp_path):
 def test_search_plan_measured_winner_and_evidence():
     """Injected measurements decide among the verified candidates; the
     plan records predicted AND measured times with the winner marked."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     comp = CompressionConfig()
     wtree = _wtree(jax.random.PRNGKey(0), w=4)
     fake = {"dense": 5e-3, "randk_shared": 2e-3, "q8_ring": 1e-3}
@@ -308,7 +309,7 @@ def test_search_plan_measured_winner_and_evidence():
 
 
 def test_search_plan_prediction_only_when_verify_zero():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     wtree = _wtree(jax.random.PRNGKey(0), w=4)
     boom = lambda c, t, k: (_ for _ in ()).throw(AssertionError)  # noqa
     plan = tune.search_plan(
@@ -327,7 +328,7 @@ def test_measured_omega_lands_in_tune_plan(tmp_path):
     certificate in the EF-BV eta/nu derivation, the plan records the
     value AND its provenance (v6 fields), and both survive the
     strict-JSON round trip."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     wtree = _wtree(jax.random.PRNGKey(0), w=4)
     comp = CompressionConfig(compressor="natural")
     kw = dict(modes=("efbv",), link=tune.LinkModel.nominal(),
@@ -357,7 +358,7 @@ def test_no_certificate_codec_warns_with_structured_event():
     dashboard can alert on, not a lost stdout line."""
     from repro import obs
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     wtree = _wtree(jax.random.PRNGKey(0), w=4)
     comp = CompressionConfig(compressor="topk",
                              compressor_kwargs=(("q", 0.25),))
@@ -378,7 +379,7 @@ def test_autotune_measured_omega_lazy_only_on_miss(tmp_path):
     """``omega_fn`` mirrors ``hide_fn``: invoked once on a cache miss
     with measured verification, never on a hit — and the cached plan
     round-trips the measured value."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     comp = CompressionConfig(comm_mode="auto", compressor="natural")
     params = {"w": jax.ShapeDtypeStruct((16, 8), jnp.float32)}
     calls = []
@@ -449,7 +450,7 @@ def test_search_plan_wire_grids_cross_product():
                           moe_wire="q8", act_wire="q8"),
         cfg, None, w=4, tokens_per_worker=64,
     ).extra_traffic()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     wtree = _wtree(jax.random.PRNGKey(0), w=4)
     plan = tune.search_plan(
         comp, wtree, mesh, 4, modes=("dense", "randk_shared"),
@@ -471,7 +472,7 @@ def test_search_plan_wire_grids_cross_product():
 
 
 def test_autotune_cache_hit_skips_search(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     comp = CompressionConfig(comm_mode="auto")
     params = {"w": jax.ShapeDtypeStruct((16, 8), jnp.float32),
               "b": jax.ShapeDtypeStruct((8,), jnp.float32)}

@@ -351,7 +351,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist.collectives import randk_shared_mean
 from repro.launch.hlo_stats import collective_bytes
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 w, d, ratio = 8, 1024, 0.05
 k = round(ratio * d)
 wtree = {"a": jax.device_put(

@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.comm.wire import encode_decode_workers, encode_meta_free, leaf_key
+from repro.obs.trace import span
 
 if TYPE_CHECKING:  # import cycle: core.shift_rules routes through Channel
     from repro.core.compressors import Compressor
@@ -124,13 +125,21 @@ class Channel:
         the rule's ``apply``.  Subclasses that pipeline (the bucketed
         ``AsyncChannel``) override the SCHEDULE only — the per-leaf key
         folding (global tree positions) keeps any re-schedule bit-exact
-        with this one.  Returns ``(g_bar, h_new, h_bar_new, bits)``.
+        with this one.  The phases run under the name scopes
+        ``message``, ``exchange`` and ``shift`` (``repro.obs.span``: pure
+        trace metadata) — every schedule uses the same three.  Returns
+        ``(g_bar, h_new, h_bar_new, bits)``.
         """
         k_msg, k_aux, k_agg = jax.random.split(key, 3)
-        m, bits = rule.message(q, k_msg, wgrads, h)
-        aux, extra = rule.aux(k_aux, wgrads, h)
-        m_bar = self.reduce_mean(k_agg, m)
-        g_bar, h_new, hb_new = rule.apply(wgrads, m, m_bar, h, h_bar, aux)
+        with span("message"):
+            m, bits = rule.message(q, k_msg, wgrads, h)
+        with span("shift"):
+            aux, extra = rule.aux(k_aux, wgrads, h)
+        with span("exchange"):
+            m_bar = self.reduce_mean(k_agg, m)
+        with span("shift"):
+            g_bar, h_new, hb_new = rule.apply(wgrads, m, m_bar, h, h_bar,
+                                              aux)
         return g_bar, h_new, hb_new, bits + extra
 
     def fused_round(self, rule, q: Compressor, key: jax.Array,
@@ -156,9 +165,13 @@ class Channel:
         bits = jnp.zeros((), jnp.float32)
         for leaf in jax.tree_util.tree_leaves(msgs):
             bits = bits + rule.message_bits_aot(q, leaf)
-        aux, extra = rule.aux(k_aux, msgs, h)
-        m_bar = self.reduce_mean(k_agg, msgs)
-        g_bar, h_new, hb_new = rule.apply(msgs, msgs, m_bar, h, h_bar, aux)
+        with span("shift"):
+            aux, extra = rule.aux(k_aux, msgs, h)
+        with span("exchange"):
+            m_bar = self.reduce_mean(k_agg, msgs)
+        with span("shift"):
+            g_bar, h_new, hb_new = rule.apply(msgs, msgs, m_bar, h, h_bar,
+                                              aux)
         return g_bar, h_new, hb_new, bits + extra
 
     def all_to_all(self, q: Compressor, key: jax.Array, x: jax.Array):

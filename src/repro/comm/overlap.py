@@ -44,6 +44,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.comm.channel import AGGREGATION_MODES, Channel
 from repro.comm.wire import encode_decode_workers, leaf_key
+from repro.obs.trace import span
 
 tmap = jax.tree_util.tree_map
 
@@ -287,21 +288,25 @@ class AsyncChannel(Channel):
         reduced: list = [None] * n
         bits = jnp.zeros((), jnp.float32)
 
-        for b in plan.buckets:
+        for bi, b in enumerate(plan.buckets):
             for i in b.indices:
-                m, bl = rule.message_leaf(
-                    q, leaf_key(k_msg, i), g_leaves[i], h_leaves[i]
-                )
+                with span("message"):
+                    m, bl = rule.message_leaf(
+                        q, leaf_key(k_msg, i), g_leaves[i], h_leaves[i]
+                    )
+                    bits = bits + bl
                 msgs[i] = m
-                bits = bits + bl
-            hd = self._reduce_bucket(k_agg, msgs, b, spec_leaves)
+            with span("exchange"), span(f"bucket_{bi}"):
+                hd = self._reduce_bucket(k_agg, msgs, b, spec_leaves)
             for j, i in enumerate(hd.bucket.indices):
                 reduced[i] = hd.values[j]
 
         m_tree = jax.tree_util.tree_unflatten(treedef, msgs)
         m_bar = jax.tree_util.tree_unflatten(treedef, reduced)
-        aux, extra = rule.aux(k_aux, wgrads, h)
-        g_bar, h_new, hb_new = rule.apply(wgrads, m_tree, m_bar, h, h_bar, aux)
+        with span("shift"):
+            aux, extra = rule.aux(k_aux, wgrads, h)
+            g_bar, h_new, hb_new = rule.apply(wgrads, m_tree, m_bar, h,
+                                              h_bar, aux)
         return g_bar, h_new, hb_new, bits + extra
 
     def fused_round(self, rule, q, key, msgs, h, h_bar):
@@ -329,16 +334,19 @@ class AsyncChannel(Channel):
         reduced: list = [None] * len(leaves)
         bits = jnp.zeros((), jnp.float32)
 
-        for b in plan.buckets:
+        for bi, b in enumerate(plan.buckets):
             for i in b.indices:
                 bits = bits + rule.message_bits_aot(q, leaves[i])
-            hd = self._reduce_bucket(k_agg, leaves, b, spec_leaves)
+            with span("exchange"), span(f"bucket_{bi}"):
+                hd = self._reduce_bucket(k_agg, leaves, b, spec_leaves)
             for j, i in enumerate(hd.bucket.indices):
                 reduced[i] = hd.values[j]
 
         m_bar = jax.tree_util.tree_unflatten(treedef, reduced)
-        aux, extra = rule.aux(k_aux, msgs, h)
-        g_bar, h_new, hb_new = rule.apply(msgs, msgs, m_bar, h, h_bar, aux)
+        with span("shift"):
+            aux, extra = rule.aux(k_aux, msgs, h)
+            g_bar, h_new, hb_new = rule.apply(msgs, msgs, m_bar, h, h_bar,
+                                              aux)
         return g_bar, h_new, hb_new, bits + extra
 
     def push_mean(self, q, key, wtree):

@@ -212,21 +212,29 @@ def _ring_allreduce_fused(key: jax.Array, x: jax.Array, axis: str, n: int,
         q8_dequant_add_2d,
         q8_quantize_chunk_3d,
     )
-    from repro.kernels.q8ring.ops import q8_dequant, ring_chunk_layout
+    from repro.kernels.q8ring.ops import (
+        LAYOUT_SCOPE,
+        UNIFORM_SCOPE,
+        q8_dequant,
+        ring_chunk_layout,
+    )
+    from repro.obs.trace import span
 
     if n == 1:
         return x
     shape = x.shape
     d = int(x.size)
     rows_c, block = ring_chunk_layout(d, n, codec.block_rows)
-    flat = x.reshape(-1).astype(jnp.float32)
-    chunks = jnp.pad(flat, (0, n * rows_c * LANE - d)).reshape(
-        n, rows_c, LANE
-    )
+    with span(LAYOUT_SCOPE):
+        flat = x.reshape(-1).astype(jnp.float32)
+        chunks = jnp.pad(flat, (0, n * rows_c * LANE - d)).reshape(
+            n, rows_c, LANE
+        )
     interp = codec.run_interpret
 
     def encode_send(k, ch, cid):
-        u = jax.random.uniform(k, (rows_c, LANE))
+        with span(UNIFORM_SCOPE):
+            u = jax.random.uniform(k, (rows_c, LANE))
         return q8_quantize_chunk_3d(ch, u, cid, block_rows=block,
                                     interpret=interp)
 
@@ -243,7 +251,8 @@ def _ring_allreduce_fused(key: jax.Array, x: jax.Array, axis: str, n: int,
         key, chunks, axis, n,
         encode_send=encode_send, decode_add=decode_add, decode=decode,
     )
-    return final.reshape(-1)[:d].reshape(shape)
+    with span(LAYOUT_SCOPE):
+        return final.reshape(-1)[:d].reshape(shape)
 
 
 def q8_ring_tree_mean(

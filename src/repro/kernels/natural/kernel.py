@@ -62,5 +62,6 @@ def shifted_natural_2d(g, h, u, *, block_rows: int = DEFAULT_BLOCK_ROWS,
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(g.shape, g.dtype),
+        name="shifted_natural_2d",
         interpret=interpret,
     )(g, h, u)

@@ -111,6 +111,7 @@ def q8_quantize_2d(x, u, *, block_rows: int = DEFAULT_BLOCK_ROWS,
             jax.ShapeDtypeStruct(x.shape, jnp.int8),
             _scale_shape(r // block_rows),
         ],
+        name="q8_quantize_2d",
         interpret=interpret,
     )(x, u)
     return q, _compact(s3)
@@ -150,6 +151,7 @@ def q8_quantize_chunk_3d(chunks, u, chunk_id, *,
             jax.ShapeDtypeStruct((r, LANE), jnp.int8),
             _scale_shape(r // block_rows),
         ],
+        name="q8_quantize_chunk_3d",
         interpret=interpret,
     )(jnp.asarray(chunk_id, jnp.int32).reshape(1), chunks, u)
     return q, _compact(s3)
@@ -171,5 +173,6 @@ def q8_dequant_add_2d(q, scales, acc, *, block_rows: int = DEFAULT_BLOCK_ROWS,
         in_specs=[tile, _scale_spec(lambda i: (i, 0, 0)), tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        name="q8_dequant_add_2d",
         interpret=interpret,
     )(q, jnp.broadcast_to(scales[:, :, None], (nb, 1, LANE)), acc)

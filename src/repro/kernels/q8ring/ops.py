@@ -34,6 +34,13 @@ from repro.kernels.q8ring.kernel import (
     q8_dequant_add_2d,
     q8_quantize_2d,
 )
+from repro.obs.trace import span
+
+#: name scopes of the codec's non-kernel work (``repro.obs.span``): the
+#: uniforms of stochastic rounding, and the f32 (rows, 128) lane layout
+#: in and out of the kernels
+UNIFORM_SCOPE = "q8/uniform"
+LAYOUT_SCOPE = "q8/layout"
 
 
 def _tile_rows(rows: int, block_rows: int):
@@ -75,10 +82,10 @@ def q8_dequant(q, scales, *, block: int, interpret: bool = True):
     """Dequantize a (R, 128) int8 block with per-tile scales: fused
     dequant-add against a zero accumulator (same single kernel serves
     both the receive-accumulate and plain-decode paths)."""
-    return q8_dequant_add_2d(
-        q, scales, jnp.zeros(q.shape, jnp.float32), block_rows=block,
-        interpret=interpret,
-    )
+    with span(LAYOUT_SCOPE):
+        acc = jnp.zeros(q.shape, jnp.float32)
+    return q8_dequant_add_2d(q, scales, acc, block_rows=block,
+                             interpret=interpret)
 
 
 @dataclass(frozen=True)
@@ -112,8 +119,10 @@ class FusedQ8(Unbiased):
     def encode(self, key, x):
         d = int(x.size)
         rows, block, rows_pad = q8_layout(d, self.block_rows)
-        x2 = to_lanes(x, rows_pad)
-        u = jax.random.uniform(key, x2.shape)
+        with span(LAYOUT_SCOPE):
+            x2 = to_lanes(x, rows_pad)
+        with span(UNIFORM_SCOPE):
+            u = jax.random.uniform(key, x2.shape)
         q, scales = q8_quantize_2d(
             x2, u, block_rows=block, interpret=self.run_interpret
         )
@@ -127,11 +136,12 @@ class FusedQ8(Unbiased):
         block = payload["q"].shape[0] // nb
         out = q8_dequant(payload["q"], payload["scale"], block=block,
                          interpret=self.run_interpret)
-        return (
-            jnp.ravel(out)[:d]
-            .reshape(shape_dtype.shape)
-            .astype(shape_dtype.dtype)
-        )
+        with span(LAYOUT_SCOPE):
+            return (
+                jnp.ravel(out)[:d]
+                .reshape(shape_dtype.shape)
+                .astype(shape_dtype.dtype)
+            )
 
     def omega(self, d):
         return d / (4.0 * LEVELS**2)

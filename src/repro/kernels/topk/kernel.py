@@ -62,5 +62,6 @@ def block_topk_2d(x, *, k: int, block_rows: int = DEFAULT_BLOCK_ROWS,
         in_specs=[spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name="block_topk_2d",
         interpret=interpret,
     )(x)

@@ -93,6 +93,7 @@ def wkv6_pallas(r, k, v, w, u, *, chunk: int = DEFAULT_CHUNK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ) if not interpret else None,
+        name="wkv6_pallas",
         interpret=interpret,
     )(r, k, v, w, u)
     return y, s_fin

@@ -190,7 +190,9 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh, w: int,
     returned STATE is bit-exact with ``diag=False`` (pinned in
     tests/test_obs.py): diagnostics consume no randomness and feed
     nothing back.  Phases are annotated with ``repro.obs.span`` — pure
-    trace metadata, no runtime ops, no extra compilations.
+    trace metadata, no runtime ops, no extra compilations: ``train/grads``,
+    ``train/round`` (whose ``message``, ``exchange`` and ``shift`` the
+    channel opens) and ``train/apply``.
     """
     from repro.obs import span
     if getattr(tcfg, "train_attn_chunk", 0) and tcfg.train_attn_chunk > 0:
@@ -312,8 +314,9 @@ def build_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh, w: int,
                     )
                 # bound the shift-tracking drift of lossy aggregation:
                 # every N rounds h_bar resyncs to the exact worker mean
-                h_bar = resync_h_bar(h, h_bar, state.step,
-                                     comp.drift_resync_every)
+                with span("shift"):
+                    h_bar = resync_h_bar(h, h_bar, state.step,
+                                         comp.drift_resync_every)
             with span("train/apply"):
                 new_params, opt = optimizer.update(
                     g_bar, state.opt, state.params
@@ -608,10 +611,15 @@ def main(argv=None):
                          "(h_bar drift, EF error norm) in the metrics "
                          "dict — the returned train STATE stays "
                          "bit-exact with the uninstrumented run")
-    ap.add_argument("--trace", action="store_true",
-                    help="record host wall-clock spans per phase "
-                         "(encode/reduce/apply) and include the span "
-                         "table in the run summary")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="write a jax.profiler trace of the run's steps "
+                         "to DIR (an .xplane.pb for TensorBoard/XProf): "
+                         "each step under StepTraceAnnotation 'train', "
+                         "making and placing its batch under "
+                         "'train/input', and its device operations under "
+                         "the step's name scopes (train/grads, "
+                         "train/round/{message,exchange,shift}, "
+                         "train/apply)")
     args = ap.parse_args(argv)
     use_compile_cache()
 
@@ -653,14 +661,10 @@ def main(argv=None):
     # structured warning events (omega_unavailable) land in --metrics_out
     obs_on = args.metrics_out is not None
     sink = None
-    recorder = None
-    if obs_on or args.trace:
+    if obs_on:
         from repro import obs
 
-        if obs_on:
-            sink = obs.JsonlSink(args.metrics_out)
-        if args.trace:
-            recorder = obs.SpanRecorder()
+        sink = obs.JsonlSink(args.metrics_out)
 
     plan = None
     if comp.enabled and comp.comm_mode == "auto":
@@ -783,58 +787,50 @@ def main(argv=None):
           f"moe_wire={comp.moe_wire} act_wire={comp.act_wire} "
           f"model_wire={comp.model_wire}")
 
-    from contextlib import nullcontext
-
     every = comp.drift_resync_every if comp.enabled else 0
-    if recorder is not None:
-        from repro.obs import recording
-
-        loop_ctx = recording(recorder)
-    else:
-        loop_ctx = nullcontext()
-    # host-side span around the step dispatch (+ readback when timing):
-    # inert without a recorder, and obs is only imported when one exists
-    step_ctx = ((lambda: obs.span("host/step"))
-                if recorder is not None else nullcontext)
+    if args.trace:
+        jax.profiler.start_trace(args.trace)
     t0 = time.time()
-    with loop_ctx:
-        for i in range(args.steps):
-            ts = time.perf_counter()
-            with step_ctx():
-                state, metrics = step_fn(
-                    state, jax.device_put(stream.batch(i), batch_sh))
-                if sink is not None or recorder is not None:
-                    jax.block_until_ready(state.params)
-            step_s = time.perf_counter() - ts
-            if bridge is not None:
-                bridge.on_step(tmap(jnp.copy, state.params), i + 1)
+    for i in range(args.steps):
+        ts = time.perf_counter()
+        with jax.profiler.StepTraceAnnotation("train", step_num=i):
+            with jax.profiler.TraceAnnotation("train/input"):
+                batch = jax.device_put(stream.batch(i), batch_sh)
+            state, metrics = step_fn(state, batch)
             if sink is not None:
-                sink.emit(obs.step_record(
-                    i,
-                    loss=float(metrics["loss"]),
-                    bits=float(metrics["bits"]),
-                    step_s=step_s,
-                    predicted_step_s=predicted_step_s,
-                    h_bar_drift=(float(metrics["h_bar_drift"])
-                                 if "h_bar_drift" in metrics else None),
-                    ef_err_norm=(float(metrics["ef_err_norm"])
-                                 if "ef_err_norm" in metrics else None),
-                    grad_sq=(float(metrics["grad_sq"])
-                             if "grad_sq" in metrics else None),
-                    shift_residual_sq=(
-                        float(metrics["shift_residual_sq"])
-                        if "shift_residual_sq" in metrics else None),
+                jax.block_until_ready(state.params)
+        step_s = time.perf_counter() - ts
+        if bridge is not None:
+            bridge.on_step(tmap(jnp.copy, state.params), i + 1)
+        if sink is not None:
+            sink.emit(obs.step_record(
+                i,
+                loss=float(metrics["loss"]),
+                bits=float(metrics["bits"]),
+                step_s=step_s,
+                predicted_step_s=predicted_step_s,
+                h_bar_drift=(float(metrics["h_bar_drift"])
+                             if "h_bar_drift" in metrics else None),
+                ef_err_norm=(float(metrics["ef_err_norm"])
+                             if "ef_err_norm" in metrics else None),
+                grad_sq=(float(metrics["grad_sq"])
+                         if "grad_sq" in metrics else None),
+                shift_residual_sq=(
+                    float(metrics["shift_residual_sq"])
+                    if "shift_residual_sq" in metrics else None),
+            ))
+            # resync_h_bar fires inside jit at (step % N) == N-1;
+            # mirror the event host-side from the same arithmetic
+            if every and (i % every) == every - 1:
+                sink.emit(obs.event_record(
+                    "drift_resync", i, every=every,
                 ))
-                # resync_h_bar fires inside jit at (step % N) == N-1;
-                # mirror the event host-side from the same arithmetic
-                if every and (i % every) == every - 1:
-                    sink.emit(obs.event_record(
-                        "drift_resync", i, every=every,
-                    ))
-            if i % max(1, args.steps // 10) == 0 or i == args.steps - 1:
-                print(f"step {i:4d}  loss {float(metrics['loss']):.4f}  "
-                      f"bits {float(metrics['bits']):.3e}  "
-                      f"({time.time()-t0:.1f}s)")
+        if i % max(1, args.steps // 10) == 0 or i == args.steps - 1:
+            print(f"step {i:4d}  loss {float(metrics['loss']):.4f}  "
+                  f"bits {float(metrics['bits']):.3e}  "
+                  f"({time.time()-t0:.1f}s)")
+    if args.trace:
+        jax.profiler.stop_trace()
     if bridge is not None:
         bridge.drain()
         s = bridge.stats()
@@ -846,22 +842,10 @@ def main(argv=None):
     if sink is not None:
         from repro import obs
 
-        spans = recorder.snapshot() if recorder is not None else None
-        sink.emit(obs.summary_record("train", spans=spans))
+        sink.emit(obs.summary_record("train"))
         sink.close()
         print(obs.summary_table(obs.read_jsonl(args.metrics_out),
                                 name=args.arch))
-        if spans:
-            rows = [(n, s["count"], f"{s['mean_s']:.3e}s")
-                    for n, s in sorted(spans.items())]
-            print(obs.format_table("host spans", ["span", "count", "mean"],
-                                   rows))
-    elif recorder is not None:
-        rows = [(n, s["count"], f"{s['mean_s']:.3e}s")
-                for n, s in sorted(recorder.snapshot().items())]
-        from repro import obs
-
-        print(obs.format_table("host spans", ["span", "count", "mean"], rows))
     return state
 
 

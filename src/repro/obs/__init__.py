@@ -8,8 +8,8 @@ discipline (``sink``), one export surface (``export``):
                strict-JSON ``StepRecord`` schema and THE repo-wide
                ``finite_or_none``/``sanitize_tree`` helpers.
   ``trace``    ``span(name)`` — ``jax.named_scope``/``TraceAnnotation``
-               inside jit (zero runtime ops, no recompiles) plus
-               host wall-clock spans into an active ``SpanRecorder``;
+               inside jit (zero runtime ops, no recompiles), read
+               from a profiler trace of the compiled step;
                ``StampRecorder`` for the overlap channel's
                reduce_start/finish call windows.
   ``sink``     rotating strict-JSONL, memory, tee, null sinks; every
@@ -56,10 +56,7 @@ from repro.obs.sink import (
     write_strict_json,
 )
 from repro.obs.trace import (
-    SpanRecorder,
     StampRecorder,
-    active_recorder,
-    recording,
     span,
 )
 from repro.obs.export import (
@@ -88,10 +85,8 @@ __all__ = [
     "NullSink",
     "RECORD_KINDS",
     "SCHEMA_VERSION",
-    "SpanRecorder",
     "StampRecorder",
     "TeeSink",
-    "active_recorder",
     "array_distortion",
     "check_jsonl",
     "distortion_floats",
@@ -102,7 +97,6 @@ __all__ = [
     "tree_distortion",
     "prometheus_text",
     "read_jsonl",
-    "recording",
     "run_record",
     "sanitize_tree",
     "span",

@@ -8,8 +8,10 @@ random weights from a fixed seed, and checks what comes out.
   python chip_smoke.py             one chip: DIANA with the q8_block
                                    codec on the dense comm mode, so every
                                    param leaf goes through the compiled
-                                   q8 Pallas kernels; plus one leaf's
-                                   kernels against ``kernels/q8ring/ref.py``
+                                   q8 Pallas kernels; plus the kernels on
+                                   an MLP leaf and on the embedding (also
+                                   as a four-way ring chunk) against
+                                   ``kernels/q8ring/ref.py``
   python chip_smoke.py --chips 4   four chips, W = 4 data-parallel
                                    workers: DIANA over the dense, the
                                    q8_ring_overlap and the
@@ -142,54 +144,101 @@ def run_steps(compiled, state, batches):
     return state, metrics, times
 
 
-def check_leaf_kernels(leaf):
-    """The chip's q8_quantize_2d + q8_dequant against the jnp oracles of
-    ``kernels/q8ring/ref.py`` on one real leaf, with the same uniforms."""
-    import jax
-    import jax.numpy as jnp
+def _check_against_ref(what, q, s, out, ref, acc=None):
+    """One kernel pass against its oracles: the int8 payload and the
+    scales, and ``out`` = acc + dequant(payload) (acc None: zero).  The
+    sum may round once less or once more than the oracle's, so its
+    bound is relative to |acc| + |dequant|, not to the sum."""
     import numpy as np
 
-    from repro.kernels.q8ring.kernel import q8_quantize_2d
-    from repro.kernels.q8ring.ops import q8_dequant, q8_layout, to_lanes
+    qr, sr, out_ref = ref
+    q, qr = np.asarray(q, np.int32), np.asarray(qr, np.int32)
+    s, sr = np.asarray(s), np.asarray(sr)
+    out, out_ref = np.asarray(out), np.asarray(out_ref)
+    acc = np.zeros_like(out_ref) if acc is None else np.asarray(acc)
+    qdiff = int(np.abs(q - qr).max())
+    srel = float(np.max(np.abs(s - sr) / sr))
+    ddiff = np.abs(out - out_ref)
+    print(f"{what}: int8 max|q - ref| {qdiff} (share equal "
+          f"{float(np.mean(q == qr))!r}), scale max rel {srel!r} (share "
+          f"equal {float(np.mean(s == sr))!r}), dequant max|d - ref| "
+          f"{float(ddiff.max())!r} (share equal "
+          f"{float(np.mean(ddiff == 0))!r})")
+    check(qdiff <= 1, f"{what}: int8 payload off the reference by {qdiff}")
+    check(srel <= 1e-6, f"{what}: scales off the reference by rel {srel}")
+    check((ddiff <= 1e-6 * (np.abs(acc) + np.abs(out_ref - acc))).all(),
+          f"{what}: dequant off the reference")
+
+
+def check_leaf_kernels(leaf, ring_chunks: int = 0):
+    """The chip's q8_quantize_2d + q8_dequant against the jnp oracles of
+    ``kernels/q8ring/ref.py`` on one real leaf, with the same uniforms;
+    with ``ring_chunks`` = n also the ring hop's kernels on the leaf cut
+    as an n-way ring cuts it: q8_quantize_chunk_3d on the last chunk and
+    q8_dequant_add_2d of its payload onto the first."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.q8ring.kernel import (
+        LANE,
+        q8_dequant_add_2d,
+        q8_quantize_2d,
+        q8_quantize_chunk_3d,
+    )
+    from repro.kernels.q8ring.ops import (
+        q8_dequant,
+        q8_layout,
+        ring_chunk_layout,
+        to_lanes,
+    )
     from repro.kernels.q8ring.ref import q8_dequant_add_ref, q8_quantize_ref
 
-    _, block, rows_pad = q8_layout(int(leaf.size))
+    d = int(leaf.size)
+    _, block, rows_pad = q8_layout(d)
     x = to_lanes(leaf, rows_pad)
     u = jax.random.uniform(jax.random.PRNGKey(SEED + 1), x.shape)
     q, s = q8_quantize_2d(x, u, block_rows=block, interpret=False)
     deq = q8_dequant(q, s, block=block, interpret=False)
-    qr, sr = q8_quantize_ref(x, u, block=block)
-    deq_ref = q8_dequant_add_ref(q, s, jnp.zeros_like(x), block=block)
-    q, qr = np.asarray(q, np.int32), np.asarray(qr, np.int32)
-    s, sr = np.asarray(s), np.asarray(sr)
-    deq, deq_ref = np.asarray(deq), np.asarray(deq_ref)
-    qdiff = int(np.abs(q - qr).max())
-    srel = float(np.max(np.abs(s - sr) / sr))
-    print(f"leaf {tuple(leaf.shape)}: int8 max|q - ref| {qdiff} "
-          f"(share equal {float(np.mean(q == qr))!r}), scale max rel "
-          f"{srel!r}, dequant max|d - ref| "
-          f"{float(np.abs(deq - deq_ref).max())!r}")
-    check(qdiff <= 1, f"int8 payload off the reference by {qdiff}")
-    check(srel <= 1e-6, f"scales off the reference by rel {srel}")
-    check(np.allclose(deq, deq_ref, rtol=1e-6, atol=0),
-          "dequant off the reference")
+    zeros = jnp.zeros_like(x)
+    _check_against_ref(
+        f"leaf {tuple(leaf.shape)}", q, s, deq,
+        (*q8_quantize_ref(x, u, block=block),
+         q8_dequant_add_ref(q, s, zeros, block=block)))
+    del x, u, q, s, deq, zeros
+    if not ring_chunks:
+        return
+    n = ring_chunks
+    rows_c, block = ring_chunk_layout(d, n)
+    chunks = to_lanes(leaf, n * rows_c).reshape(n, rows_c, LANE)
+    u = jax.random.uniform(jax.random.PRNGKey(SEED + 2), (rows_c, LANE))
+    q, s = q8_quantize_chunk_3d(chunks, u, n - 1, block_rows=block,
+                                interpret=False)
+    out = q8_dequant_add_2d(q, s, chunks[0], block_rows=block,
+                            interpret=False)
+    _check_against_ref(
+        f"leaf {tuple(leaf.shape)} ring chunk {n - 1} of {n} "
+        f"({rows_c // block} tiles)", q, s, out,
+        (*q8_quantize_ref(chunks[n - 1], u, block=block),
+         q8_dequant_add_ref(q, s, chunks[0], block=block)), acc=chunks[0])
 
 
 def one_chip(devices):
     dev = devices[0]
     state, step, batches = build_run("dense", devices, ONE_CHIP_BATCH,
                                      ONE_CHIP_SEQ)
-    leaf = state.params["blocks"]["mlp"]["w_up"][0].astype("float32")
     compiled, _ = compile_step(step, state, batches[0],
                                dev.memory_stats()["bytes_limit"])
     state, metrics, times = run_steps(compiled, state, batches)
+    leaves = (state.params["blocks"]["mlp"]["w_up"][0].astype("float32"),
+              state.params["embed"]["table"].astype("float32"))
     for i, m in enumerate(metrics):
         print(f"step {i} loss {m['loss']!r} bits {m['bits']!r}")
     print(f"smoke step_s (not a benchmark) {times!r}  "
           f"batch {ONE_CHIP_BATCH} x seq {ONE_CHIP_SEQ}")
     print(f"peak_bytes_in_use {dev.memory_stats()['peak_bytes_in_use']}")
     del state
-    check_leaf_kernels(leaf)
+    check_leaf_kernels(leaves[0])
+    check_leaf_kernels(leaves[1], ring_chunks=4)
 
 
 def _host(tree):

@@ -104,7 +104,7 @@ def test_q8_quantize_and_dequant_compile(one_chip, leaf):
     assert "tpu_custom_call" in dec
 
 
-@pytest.mark.parametrize("leaf", ["qk_norm", "norm", "mlp"])
+@pytest.mark.parametrize("leaf", ["qk_norm", "norm", "mlp", "embed"])
 def test_q8_ring_chunk_compiles_at_four_way(one_chip, leaf):
     n = 4
     rows_c, block = ring_chunk_layout(QWEN3_LEAVES[leaf], n)
@@ -117,7 +117,16 @@ def test_q8_ring_chunk_compiles_at_four_way(one_chip, leaf):
                                               interpret=False),
         chunks, u, cid,
     )
+    q = jax.ShapeDtypeStruct((rows_c, LANE), jnp.int8, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((rows_c // block, 1), jnp.float32,
+                             sharding=one_chip)
+    add = _compiled_hlo(
+        lambda q_, s_, acc: q8_dequant_add_2d(q_, s_, acc, block_rows=block,
+                                              interpret=False),
+        q, s, u,
+    )
     assert "tpu_custom_call" in hlo
+    assert "tpu_custom_call" in add
 
 
 def test_fused_ring_tree_mean_compiles_on_2x2(mesh_2x2):

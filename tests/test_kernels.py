@@ -10,9 +10,11 @@ from repro.kernels.natural.kernel import shifted_natural_2d
 from repro.kernels.natural.ops import shifted_natural
 from repro.kernels.natural.ref import shifted_natural_ref
 from repro.kernels.q8ring.kernel import (
+    LANE,
     q8_dequant_add_2d,
     q8_quantize_2d,
     q8_quantize_chunk_3d,
+    tiles_per_step,
 )
 from repro.kernels.q8ring.ops import FusedQ8
 from repro.kernels.q8ring.ref import q8_dequant_add_ref, q8_quantize_ref
@@ -115,16 +117,92 @@ def test_block_topk_contraction():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("rows,block", [(8, 8), (64, 8), (64, 64), (96, 32),
-                                        (1, 1)])
+#: (rows, block) of the kernel cases: one-tile leaves (28, 8 and 1 rows),
+#: and 37 and 44 tiles of 64 rows, which leave the last grid block ragged
+Q8_SHAPES = [(8, 8), (64, 8), (64, 64), (96, 32), (1, 1), (37 * 64, 64),
+             (44 * 64, 64), (28, 28)]
+
+
+def _q8_ref(block):
+    """The oracles, jitted as the kernels are: the same XLA arithmetic,
+    so kernel and oracle agree bit for bit."""
+    return (jax.jit(lambda x, u: q8_quantize_ref(x, u, block=block)),
+            jax.jit(lambda q, s, a: q8_dequant_add_ref(q, s, a, block=block)))
+
+
+@pytest.mark.parametrize("rows,block", Q8_SHAPES)
 def test_q8_quantize_matches_ref(rows, block):
     x = jax.random.normal(jax.random.PRNGKey(0), (rows, 128)) * 3.0
     u = jax.random.uniform(jax.random.PRNGKey(1), (rows, 128))
     q, s = q8_quantize_2d(x, u, block_rows=block)
-    qr, sr = q8_quantize_ref(x, u, block=block)
+    qr, sr = _q8_ref(block)[0](x, u)
     assert q.dtype == jnp.int8 and s.dtype == jnp.float32
+    assert s.shape == (rows // block, 1)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
-    np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
+
+
+@pytest.mark.parametrize("rows,block", Q8_SHAPES)
+def test_q8_dequant_add_matches_ref_bitwise(rows, block):
+    x = jax.random.normal(jax.random.PRNGKey(11), (rows, 128)) * 2.0
+    u = jax.random.uniform(jax.random.PRNGKey(12), (rows, 128))
+    acc = jax.random.normal(jax.random.PRNGKey(13), (rows, 128))
+    quantize, dequant_add = _q8_ref(block)
+    q, s = quantize(x, u)
+    out = q8_dequant_add_2d(q, s, acc, block_rows=block)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(dequant_add(q, s, acc)))
+
+
+@pytest.mark.parametrize("rows,block", [(37 * 64, 64), (44 * 64, 64),
+                                        (28, 28)])
+def test_q8_quantize_chunk_matches_ref(rows, block):
+    """The chunk kernel on a ragged chunk tile count (and a one-tile
+    chunk), chunk id traced as in the ring loop."""
+    chunks = jax.random.normal(jax.random.PRNGKey(14), (4, rows, 128)) * 3.0
+    u = jax.random.uniform(jax.random.PRNGKey(15), (rows, 128))
+    q, s = jax.jit(
+        lambda c, u_, i: q8_quantize_chunk_3d(c, u_, i, block_rows=block)
+    )(chunks, u, jnp.int32(2))
+    qr, sr = _q8_ref(block)[0](chunks[2], u)
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(qr))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
+
+
+def _pallas_grids(fn, *args):
+    """{kernel name: grid} of every pallas_call in fn's jaxpr."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"], eqn.params["grid_mapping"].grid
+            for p in eqn.params.values():
+                sub = getattr(p, "jaxpr", p)
+                if hasattr(sub, "eqns"):
+                    yield from walk(sub)
+    return dict(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("n_tiles,block,steps", [
+    (37, 64, 3), (44, 64, 3), (4748, 64, 297),   # an embed ring chunk
+    (18992, 64, 1187), (1, 28, 1), (1, 1, 1), (300, 8, 3), (8, 256, 2)])
+def test_q8_kernels_take_many_tiles_per_grid_step(n_tiles, block, steps):
+    """Each grid step covers T scale tiles (16 of 64 rows), not one:
+    cdiv(n_tiles, T) steps, on all three kernels."""
+    assert steps == -(-n_tiles // tiles_per_step(n_tiles, block))
+    f32 = jax.ShapeDtypeStruct((n_tiles * block, LANE), jnp.float32)
+    grids = _pallas_grids(
+        lambda x, u, c: (
+            q8_quantize_2d(x, u, block_rows=block),
+            q8_quantize_chunk_3d(c, u, 1, block_rows=block),
+            q8_dequant_add_2d(x.astype(jnp.int8),
+                              jnp.ones((n_tiles, 1)), x, block_rows=block),
+        ),
+        f32, f32, jax.ShapeDtypeStruct((4, n_tiles * block, LANE),
+                                       jnp.float32),
+    )
+    assert grids == {"q8_quantize_2d": (steps,),
+                     "q8_quantize_chunk_3d": (steps,),
+                     "q8_dequant_add_2d": (steps,)}
 
 
 def test_q8_quantize_chunk_select_matches_2d():

@@ -4,22 +4,26 @@ These are the numerators of ``mfu`` and of ``q8_roofline``.  They count
 what the model and the codec need, not what an implementation happens
 to do: recomputation, padding, uniforms and zero accumulators are left
 out, so an implementation that does extra work shows a lower share.
+An architecture's forward FLOPs are counted by its reference module
+(``reference/archs/<arch_type>.py``) from the pieces below.
 """
 
 from __future__ import annotations
 
 import math
 
+from reference import model as RM
+
 LANE = 128
 
 
-def _attn_block_matmul_params(m: dict) -> int:
+def attn_block_matmul_params(m: dict) -> int:
     d, h, kv = m["d_model"], m["n_heads"], m["n_kv_heads"]
     hd = m.get("head_dim") or d // h
     return d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * m["d_ff"]
 
 
-def _attn_flops_per_token(m: dict, seq: int) -> float:
+def attn_flops_per_token(m: dict, seq: int) -> float:
     """Forward QK^T and PV of causal attention, per token of one layer:
     2 products x 2 flops x heads x head_dim x the mean context
     (seq + 1) / 2."""
@@ -28,7 +32,7 @@ def _attn_flops_per_token(m: dict, seq: int) -> float:
     return 4.0 * h * hd * (seq + 1) / 2
 
 
-def _ssd_flops_per_token(m: dict) -> float:
+def ssd_flops_per_token(m: dict) -> float:
     """Forward matmuls of the chunked SSD algorithm (arXiv:2405.21060,
     section 7) per token of one layer, chunk length L from the
     configuration: the causal half of the intra-chunk C.B^T and of its
@@ -41,7 +45,7 @@ def _ssd_flops_per_token(m: dict) -> float:
     return 2 * ctx * n + 2 * ctx * h * p + 4 * n * h * p
 
 
-def _mamba_matmul_params(m: dict) -> int:
+def mamba_matmul_params(m: dict) -> int:
     d = m["d_model"]
     d_inner = m["mamba_expand"] * d
     h = d_inner // m["mamba_head_dim"]
@@ -49,26 +53,18 @@ def _mamba_matmul_params(m: dict) -> int:
     return d * (2 * d_inner + 2 * n + h) + d_inner * d
 
 
+def head_params(m: dict) -> int:
+    return m["d_model"] * m["vocab_size"]
+
+
 def model_flops_per_token(m: dict, seq: int) -> float:
     """Training FLOPs per token: 3 x the forward pass (forward, and a
     backward of twice its cost), where the forward is 2 flops per weight
     of every matrix product applied to the token (a shared block once
     for each time it is applied; the output head; not the embedding
-    lookup) plus causal attention, plus the SSD matmuls for Mamba-2."""
-    head = m["d_model"] * m["vocab_size"]
-    if m["arch_type"] == "dense":
-        n_attn = m["n_layers"]
-        fwd = 2.0 * (n_attn * _attn_block_matmul_params(m) + head)
-        fwd += n_attn * _attn_flops_per_token(m, seq)
-    elif m["arch_type"] == "hybrid":
-        n_attn = m["n_layers"] // m["attn_every"]
-        fwd = 2.0 * (m["n_layers"] * _mamba_matmul_params(m)
-                     + n_attn * _attn_block_matmul_params(m) + head)
-        fwd += n_attn * _attn_flops_per_token(m, seq)
-        fwd += m["n_layers"] * _ssd_flops_per_token(m)
-    else:
-        raise ValueError(f"no FLOP count for arch_type {m['arch_type']!r}")
-    return 3.0 * fwd
+    lookup) plus causal attention and any other sequence mixer (the SSD
+    matmuls of Mamba-2), as the architecture's module adds them up."""
+    return 3.0 * RM.arch(m["arch_type"]).forward_flops_per_token(m, seq)
 
 
 def _tiles(n_elems: float, block_rows: int) -> int:

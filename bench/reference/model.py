@@ -1,21 +1,28 @@
-"""Plain float32 references of the benchmarked architectures.
+"""Plain float32 references: the layers, and the lookup of an architecture.
 
 Straight ``jax.numpy``: no kernels, no chunking tricks, no sharding and
-nothing imported from the system under test.  Each function follows the
-layer equations of the published model as the configuration file states
-them (its ``assumed`` list names where the system departs from the paper
-and the reference follows the system's stated choice):
+nothing imported from the system under test.  Each layer follows the
+equations of the published model as the configuration file states them
+(its ``assumed`` list names where the system departs from the paper and
+the reference follows the system's stated choice):
 
-* ``dense`` (Qwen3): pre-norm decoder blocks of grouped-query causal
-  attention with per-head RMS q/k norms and half-split rotary embeddings,
-  then a SwiGLU MLP; tied or untied output head.
-* ``hybrid`` (Zamba2): periods of ``attn_every`` Mamba-2 layers, each
-  period followed by one application of a shared attention + MLP block
-  (the same weights every time).  The Mamba-2 state-space mixer is
+* ``attn_block``: a pre-norm decoder block of grouped-query causal
+  attention (optional per-head RMS q/k norms, half-split rotary
+  embeddings), then a SwiGLU MLP;
+* ``mamba_layer``: a pre-norm Mamba-2 layer whose state-space mixer is
   written in its quadratic "dual" form over the whole sequence,
   y_t = sum_{s<=t} (C_t . B_s) exp(cum_t - cum_s) dt_s x_s + D x_t,
   the direct unrolling of S_t = exp(a dt_t) S_{t-1} + B_t (dt_t x_t)^T,
   y_t = C_t^T S_t + D x_t (arXiv:2405.21060, section 6).
+
+How an architecture composes them lives in a module of its own,
+``archs/<arch_type>.py``, found by the ``arch_type`` of the model
+description: ``init_params(key, m)``, ``logits(params, tokens, m, ein)``
+and ``forward_flops_per_token(m, seq)`` (the numerator of ``mfu``, by
+``counts.py``'s rules), and for the test suite ``SMOKE``, a small model
+description without its ``arch_type``, ``SMOKE_SEQ`` and ``SYSTEM_ARCH``,
+the system's arch id it is compared with.  A new architecture adds its
+module and edits nothing here.
 
 Matrix products go through a ``Matmul`` policy: float32 at the highest
 precision (the reference), or operands rounded to scaled float8 (the
@@ -26,13 +33,18 @@ from the seed feeds both.
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import math
+import pathlib
 
 import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+#: one module per architecture, ``archs/<arch_type>.py``
+ARCHS = pathlib.Path(__file__).resolve().parent / "archs"
 
 
 # --------------------------------------------------------------------------
@@ -118,7 +130,7 @@ def _normal(key, shape, std, dtype):
     return (jax.random.normal(key, shape, F32) * std).astype(dtype)
 
 
-def _attn_block_init(key, m: dict, dtype, n_scaled_layers: int):
+def attn_block_init(key, m: dict, dtype, n_scaled_layers: int):
     d, h, kv, hd = _dims(m)
     ks = jax.random.split(key, 7)
     out_std = 0.02 / math.sqrt(2 * n_scaled_layers)
@@ -144,7 +156,7 @@ def _attn_block_init(key, m: dict, dtype, n_scaled_layers: int):
     }
 
 
-def _mamba_layer_init(key, m: dict, dtype):
+def mamba_layer_init(key, m: dict, dtype):
     """Mamba-2's own initialisation: A in [1, 16], dt log-uniform in
     [1e-3, 1e-1] through the softplus inverse, D = 1, PyTorch's default
     uniform for the depthwise causal conv."""
@@ -175,27 +187,16 @@ def _mamba_layer_init(key, m: dict, dtype):
     }
 
 
-def init_params(key, m: dict):
-    """Random weights for the model described by ``m`` (a configuration
-    file's ``model`` object), in its stated dtype, from ``key``."""
-    dtype = jnp.dtype(m["dtype"])
-    ks = jax.random.split(key, 4)
+def embed_and_head_init(ks, m: dict, dtype):
+    """The embedding (from ``ks[0]``), the final norm, and the output head
+    (from ``ks[3]``; nothing when tied to the embedding)."""
     d, v = m["d_model"], m["vocab_size"]
-    layer_keys = jax.random.split(ks[1], m["n_layers"])
-    p = {"embed": {"table": _normal(ks[0], (v, d), 0.02, dtype)}}
-    if m["arch_type"] == "dense":
-        p["blocks"] = jax.vmap(
-            lambda k: _attn_block_init(k, m, dtype, m["n_layers"]))(layer_keys)
-    elif m["arch_type"] == "hybrid":
-        p["blocks"] = jax.vmap(
-            lambda k: _mamba_layer_init(k, m, dtype))(layer_keys)
-        p["shared_attn"] = _attn_block_init(ks[2], m, dtype, m["n_layers"])
-    else:
-        raise ValueError(f"no reference for arch_type {m['arch_type']!r}")
-    p["final_norm"] = {"scale": jnp.ones((d,), dtype)}
-    p["head"] = ({} if m.get("tie_embeddings")
-                 else {"w": _normal(ks[3], (d, v), 0.02, dtype)})
-    return p
+    return {
+        "embed": {"table": _normal(ks[0], (v, d), 0.02, dtype)},
+        "final_norm": {"scale": jnp.ones((d,), dtype)},
+        "head": ({} if m.get("tie_embeddings")
+                 else {"w": _normal(ks[3], (d, v), 0.02, dtype)}),
+    }
 
 
 # --------------------------------------------------------------------------
@@ -294,31 +295,57 @@ def mamba_layer(p, x, m: dict, ein: Matmul):
     return x + ein("bte,ed->btd", y, q["w_out"])
 
 
-def _scan(fn, p_stack, x):
+def scan(fn, p_stack, x):
     """Run ``x`` through a stack of layers (leading axis), recomputing
     each layer on the backward pass so long sequences fit."""
     body = jax.checkpoint(lambda y, p: (fn(p, y), None))
     return jax.lax.scan(body, x, p_stack)[0]
 
 
-def logits(params, tokens, m: dict, ein: Matmul):
-    """Logits (B, S, V) of ``tokens`` (B, S)."""
-    x = params["embed"]["table"][tokens].astype(F32)
-    if m["arch_type"] == "dense":
-        x = _scan(lambda p, y: attn_block(p, y, m, ein), params["blocks"], x)
-    else:
-        period = m["attn_every"]
-        shared = jax.checkpoint(lambda p, y: attn_block(p, y, m, ein))
-        for lo in range(0, m["n_layers"], period):
-            seg = jax.tree_util.tree_map(lambda a: a[lo: lo + period],
-                                         params["blocks"])
-            x = _scan(lambda p, y: mamba_layer(p, y, m, ein), seg, x)
-            if lo + period <= m["n_layers"]:
-                x = shared(params["shared_attn"], x)
+def embed(params, tokens):
+    return params["embed"]["table"][tokens].astype(F32)
+
+
+def head(params, x, m: dict, ein: Matmul):
+    """Logits of the last layer's output ``x``: final norm, then the
+    output head (the embedding's transpose when tied)."""
     x = rmsnorm(x, params["final_norm"]["scale"], m["norm_eps"])
     if m.get("tie_embeddings"):
         return ein("bsd,vd->bsv", x, params["embed"]["table"])
     return ein("bsd,dv->bsv", x, params["head"]["w"])
+
+
+# --------------------------------------------------------------------------
+# The architecture, by name
+# --------------------------------------------------------------------------
+
+
+def arch(arch_type: str):
+    """The module of an architecture, ``archs/<arch_type>.py``."""
+    return _load_arch(ARCHS / f"{arch_type}.py")
+
+
+@functools.cache
+def _load_arch(path: pathlib.Path):
+    if not path.is_file():
+        raise ValueError(f"no reference for arch_type {path.stem!r}: "
+                         f"missing {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"reference_arch_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def init_params(key, m: dict):
+    """Random weights for the model described by ``m`` (a configuration
+    file's ``model`` object), in its stated dtype, from ``key``."""
+    return arch(m["arch_type"]).init_params(key, m)
+
+
+def logits(params, tokens, m: dict, ein: Matmul):
+    """Logits (B, S, V) of ``tokens`` (B, S)."""
+    return arch(m["arch_type"]).logits(params, tokens, m, ein)
 
 
 def row_losses(params, tokens, m: dict, ein: Matmul):

@@ -44,7 +44,15 @@ none:
   ``bench/limits/<workload>.json``;
 * a per-layer metric is ``bench/metrics/<metric>.py`` with ``LAYER``,
   ``UNIT``, ``MOVES`` and ``read(ctx)``, returning a number or ``None``
-  when the trace holds nothing for it (the metric is then left out).
+  when the trace holds nothing for it (the metric is then left out);
+* an architecture is ``bench/reference/archs/<arch_type>.py``, named by
+  the ``arch_type`` of the config's ``model``: its plain reference
+  (``init_params(key, m)``, ``logits(params, tokens, m, ein)``, built
+  from the layers of ``bench/reference/model.py``), its forward FLOPs per
+  token (``forward_flops_per_token(m, seq)``, from the pieces of
+  ``bench/counts.py``; ``mfu`` reads three times it), and a smoke case
+  (``SMOKE``, ``SMOKE_SEQ``, ``SYSTEM_ARCH``) that
+  ``bench/test_bench_reference.py`` compares with the system on the CPU.
 
 Worked example: a four-chip cell of qwen3-0.6b whose DIANA messages are
 summed by XLA's all-reduce instead of the q8 ring needs
@@ -56,6 +64,18 @@ readings, ``bench/calibrate.py``), and a ``workloads`` entry
 "dp4.dense.s1024", "chips": 4, "why": ...}``.  A metric that should
 also read there either has no ``workloads`` key or gains the cell's
 name in it.
+
+Worked example of a new architecture: a one-chip cell of the system's
+``qwen2-moe-a2.7b`` (``arch_type`` ``moe``) adds
+``bench/reference/archs/moe.py`` (``init_params``, ``logits`` and
+``forward_flops_per_token``, composed from the shared layers and any
+layer of its own written in the file; ``SMOKE``, ``SMOKE_SEQ`` and
+``SYSTEM_ARCH = "qwen2-moe-a2.7b"``), ``bench/configs/<config>.json``
+(``"arch": "qwen2-moe-a2.7b"``, ``"model": {"arch_type": "moe", ...}``),
+``bench/traffic/<traffic>.json``, ``bench/limits/<workload>.json`` and
+its ``configs`` and ``workloads`` entries in ``BENCHMARK.json``.  It
+edits no file: the reference, the FLOP count behind ``mfu``, the check
+and the smoke test all find the module by its name.
 """
 
 from __future__ import annotations

@@ -2,14 +2,21 @@
 
 The references (``bench/reference/``) import nothing of the system; here
 the test does, to show that both compute the same thing: loss and
-gradients of both architectures, the q8 codec against the kernels' own
-oracle (``kernels/q8ring/ref.py``), and whole DIANA + AdamW steps of a
-tiny cell through the harness against the reference's.
+gradients of every architecture in ``bench/reference/archs/`` (each
+module brings its own smoke model and the system's arch id), the q8
+codec against the kernels' own oracle (``kernels/q8ring/ref.py``), and
+whole DIANA + AdamW steps of a tiny cell through the harness against
+the reference's.  The architecture lookup is shown to take a new module
+as a new file, and the counts behind ``mfu`` and ``q8_roofline`` are
+pinned to the float.
 """
 
 from __future__ import annotations
 
+import dataclasses
+import math
 import pathlib
+import shutil
 import sys
 
 import numpy as np
@@ -22,36 +29,34 @@ sys.path.insert(0, str(HERE.parent / "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+import counts  # noqa: E402
 import run  # noqa: E402
 from reference import model as RM  # noqa: E402
 from reference import train as RT  # noqa: E402
 
-DENSE = {"arch_type": "dense", "n_layers": 2, "d_model": 64, "n_heads": 4,
-         "n_kv_heads": 2, "head_dim": 16, "d_ff": 128, "vocab_size": 256,
-         "qk_norm": True, "rope_theta": 1e6, "norm_eps": 1e-5,
-         "tie_embeddings": True, "dtype": "float32"}
-HYBRID = {"arch_type": "hybrid", "n_layers": 4, "d_model": 64, "n_heads": 4,
-          "n_kv_heads": 4, "head_dim": 16, "d_ff": 128, "vocab_size": 256,
-          "ssm_state": 16, "attn_every": 2, "rwkv_head_dim": 16,
-          "conv_kernel": 4, "norm_eps": 1e-5, "rope_theta": 1e4,
-          "qk_norm": False, "tie_embeddings": False, "dtype": "float32"}
-ARCH = {"dense": "qwen3-0.6b", "hybrid": "zamba2-1.2b"}
+ARCHS = sorted(p.stem for p in RM.ARCHS.glob("*.py"))
 
 
-def _system_cfg(m):
+def smoke(arch_type: str) -> dict:
+    """The smoke model of the architecture module ``arch_type``."""
+    return {**RM.arch(arch_type).SMOKE, "arch_type": arch_type}
+
+
+def _system_cfg(arch_type: str, m: dict):
     from repro.configs import get_config
 
-    fields = {k: v for k, v in m.items() if not k.startswith("mamba_")}
-    return get_config(ARCH[m["arch_type"]]).with_(**fields)
+    cfg = get_config(RM.arch(arch_type).SYSTEM_ARCH)
+    known = {f.name for f in dataclasses.fields(cfg)}
+    return cfg.with_(**{k: v for k, v in m.items() if k in known})
 
 
-@pytest.mark.parametrize("m,seq", [(DENSE, 48), (HYBRID, 64)],
-                         ids=["dense", "hybrid"])
-def test_reference_loss_and_grads_match_the_system(m, seq):
+@pytest.mark.parametrize("arch_type", ARCHS)
+def test_reference_loss_and_grads_match_the_system(arch_type):
     from repro.models import model as M
 
-    m = dict(m, mamba_expand=2, mamba_head_dim=m.get("rwkv_head_dim", 0))
-    cfg = _system_cfg(m)
+    m = smoke(arch_type)
+    seq = RM.arch(arch_type).SMOKE_SEQ
+    cfg = _system_cfg(arch_type, m)
     key = jax.random.PRNGKey(3)
     params = RM.init_params(key, m)
     toks = jax.random.randint(jax.random.fold_in(key, 1), (2, seq), 0,
@@ -66,6 +71,72 @@ def test_reference_loss_and_grads_match_the_system(m, seq):
                     jax.tree_util.tree_leaves(ref_g)):
         scale = float(jnp.linalg.norm(b)) + 1e-12
         assert float(jnp.linalg.norm(a - b)) / scale < 1e-3
+
+
+def test_a_new_architecture_is_a_new_file(tmp_path, monkeypatch):
+    """A copy of ``dense.py`` under another name, in an ``archs``
+    directory of its own, is found, initialised, run and counted."""
+    for name in ARCHS:
+        shutil.copy(RM.ARCHS / f"{name}.py", tmp_path)
+    shutil.copy(RM.ARCHS / "dense.py", tmp_path / "dense_copy.py")
+    monkeypatch.setattr(RM, "ARCHS", tmp_path)
+    m, copy = smoke("dense"), smoke("dense_copy")
+    assert copy == {**m, "arch_type": "dense_copy"}
+    key = jax.random.PRNGKey(2)
+    params = RM.init_params(key, copy)
+    for a, b in zip(jax.tree_util.tree_leaves(params),
+                    jax.tree_util.tree_leaves(RM.init_params(key, m))):
+        np.testing.assert_array_equal(a, b)
+    toks = jax.random.randint(key, (2, 16), 0, m["vocab_size"])
+    np.testing.assert_array_equal(
+        RM.row_losses(params, toks, copy, RM.Matmul()),
+        RM.row_losses(params, toks, m, RM.Matmul()))
+    assert counts.model_flops_per_token(copy, 1024) == \
+        counts.model_flops_per_token(m, 1024)
+
+
+@pytest.mark.parametrize("entry", ["init_params", "row_losses", "flops"])
+def test_an_unknown_architecture_names_the_missing_file(entry):
+    m = {**smoke("dense"), "arch_type": "no_such_arch"}
+    key = jax.random.PRNGKey(0)
+    call = {
+        "init_params": lambda: RM.init_params(key, m),
+        "row_losses": lambda: RM.row_losses(
+            RM.init_params(key, smoke("dense")), jnp.zeros((1, 4), int), m,
+            RM.Matmul()),
+        "flops": lambda: counts.model_flops_per_token(m, 1024),
+    }[entry]
+    with pytest.raises(ValueError, match=r"archs/no_such_arch\.py"):
+        call()
+
+
+def _qwen3_cell_model() -> dict:
+    return run.load_cell("qwen3-0.6b.dp1.q8-diana").model
+
+
+#: the floats these counts gave when each architecture was a branch of
+#: ``counts.py``: the numerators of ``mfu`` and ``q8_roofline`` must not
+#: move when the code behind them does
+PINNED_FLOPS = [
+    (_qwen3_cell_model, 1024, 3928571904.0),
+    (lambda: smoke("hybrid"), 256, 1759680.0),
+]
+
+
+@pytest.mark.parametrize("model,seq,want", PINNED_FLOPS,
+                         ids=["qwen3-0.6b", "hybrid-smoke"])
+def test_model_flops_per_token_are_pinned(model, seq, want):
+    assert counts.model_flops_per_token(model(), seq) == want
+
+
+@pytest.mark.parametrize("chips,want", [(1, 3576881624.0),
+                                        (4, 13561518276.0)])
+def test_q8_bytes_per_step_of_qwen3_are_pinned(chips, want):
+    m = _qwen3_cell_model()
+    leaves = [(int(math.prod(a.shape)), a.dtype.itemsize)
+              for a in jax.tree_util.tree_leaves(jax.eval_shape(
+                  lambda k: RM.init_params(k, m), jax.random.PRNGKey(0)))]
+    assert counts.q8_bytes_per_step(leaves, 1, chips, 64) == want
 
 
 def test_q8_roundtrip_matches_the_kernel_oracle():
@@ -87,7 +158,7 @@ def test_q8_roundtrip_matches_the_kernel_oracle():
 
 
 def test_reference_precisions_differ_only_in_matmuls():
-    m = dict(DENSE)
+    m = smoke("dense")
     key = jax.random.PRNGKey(1)
     params = RM.init_params(key, m)
     toks = jax.random.randint(key, (1, 16), 0, m["vocab_size"])
